@@ -15,7 +15,7 @@ sizes — the interpret-mode/CPU situation).
 
 On CPU the kernels run in interpret mode, so the GB/s figures are the
 *interpreter's* — a stable regression baseline for CI, not hardware
-numbers; on a real TPU pass ``interpret=False`` for roofline rates.
+numbers; on a TPU the same calls compile the kernels.
 
 Writes ``BENCH_kernels.json`` at the repo root (consumed by
 ``benchmarks.check_regression``) and the usual rows under results/.

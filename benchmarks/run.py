@@ -12,6 +12,8 @@ from __future__ import annotations
 import argparse
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from benchmarks import (
     fault_sweep,
     fig3_incast_fct,
@@ -46,6 +48,7 @@ def main(argv=None) -> int:
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--only", default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     names = [args.only] if args.only else list(MODULES)
     for name in names:
         t0 = time.time()

@@ -14,6 +14,7 @@ import numpy as np
 from repro.config import LTPConfig, NetConfig, TrainConfig
 from repro.configs import get_config
 from repro.data import SyntheticCIFAR, batches
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build
 from repro.models.cnn import accuracy
 from repro.optim import make_optimizer
@@ -26,6 +27,7 @@ def main(argv=None):
     ap.add_argument("--loss-rate", type=float, default=0.001)
     ap.add_argument("--workers", type=int, default=8)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config("papernet").replace(d_model=16)
     api = build(cfg)

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build
 
 
@@ -21,6 +22,7 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--new", type=int, default=24)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_reduced(args.arch).replace(dtype="float32")
     api = build(cfg)

@@ -18,6 +18,7 @@ from repro.config import LTPConfig, NetConfig, TrainConfig
 from repro.configs import get_config
 from repro.checkpoint import save_checkpoint
 from repro.data import SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build
 from repro.optim import make_optimizer
 from repro.train import PSTrainer
@@ -33,6 +34,7 @@ def main():
     ap.add_argument("--batch", type=int, default=32)
     ap.add_argument("--ckpt", default="/tmp/repro_lm_ckpt")
     args = ap.parse_args()
+    enable_compile_cache()
 
     base = get_config("smollm_360m")
     if args.tiny:

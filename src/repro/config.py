@@ -173,13 +173,10 @@ class LTPConfig:
     # reduction through the fused kernels in ``repro.kernels``; "auto"
     # picks per call site — python below the measured crossover stream
     # size (``ltp_sync.AUTO_CROSSOVER_ELEMS``), pallas above it, and
-    # always python in interpret mode — so the kernel path can never be
-    # a regression.
+    # always python where the kernels are interpreted (any backend but a
+    # TPU, ``kernels.common.interpret_mode``) — so the kernel path can
+    # never be a regression.
     sync_backend: str = "python"     # python | pallas | auto
-    # Pallas interpret mode: True executes kernel bodies in the Python
-    # interpreter (the only option on CPU); set False on a real TPU to
-    # compile the fused tiles.
-    kernel_interpret: bool = True
     seed: int = 0
     # telemetry sink selection (DESIGN.md §12); None == all defaults
     # (tracker "none", zero overhead)
@@ -219,7 +216,6 @@ class RuntimeConfig:
     error_feedback: bool = False
     # PS aggregation backend: python | pallas | auto (DESIGN.md §7/§9)
     sync_backend: str = "python"
-    kernel_interpret: bool = True
     seed: int = 0
     # telemetry sink selection (DESIGN.md §12); None == tracker "none"
     obs: Optional[ObservabilityConfig] = None

@@ -25,7 +25,8 @@ Aggregation backends (DESIGN.md §7): every masked-aggregation step
 dispatches through ``apply_delivery`` / ``reduce_packet_stream`` on
 ``LTPConfig.sync_backend`` — ``python`` is the pure-jnp reference,
 ``pallas`` runs the fused ``kernels.dropfill`` / ``kernels.packet_reduce``
-tiles (one HBM pass for the whole PS hot loop; interpret mode on CPU).
+tiles (one HBM pass for the whole PS hot loop; compiled on a TPU,
+interpreted elsewhere).
 Both backends agree to float tolerance (tests/test_sync_backend.py).
 """
 from __future__ import annotations
@@ -38,10 +39,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-from repro.compat import shard_map as _shard_map
 from repro.config import LTPConfig
 from repro.core import packets as pk
+from repro.kernels import common as kcommon
 from repro.kernels import ops as kops
 from repro.models.sharding import dp_axes
 
@@ -54,7 +54,7 @@ _DP_ORDER = ("pod", "data")
 # ----------------------------------------------------------------------------
 
 #: python/pallas crossover in stream elements (W * n_packets * payload)
-#: for COMPILED kernels (``kernel_interpret=False``): below it the jnp
+#: for COMPILED kernels (a TPU backend): below it the jnp
 #: reference wins on dispatch overhead, above it the fused single-pass
 #: tiles win on memory traffic. In interpret mode the kernel body runs
 #: in the Python interpreter and never beats jnp, so "auto" always
@@ -77,8 +77,7 @@ def resolve_backend(backend: str, n_elems: int,
     return "pallas"
 
 
-def apply_delivery(packets, mask, scale=None, *, backend: str = "python",
-                   interpret: bool = True):
+def apply_delivery(packets, mask, scale=None, *, backend: str = "python"):
     """Bubble-fill + compensation gate: ``packets * mask * scale``.
 
     packets: (n_packets, payload); mask/scale: (n_packets,). The pallas
@@ -86,10 +85,11 @@ def apply_delivery(packets, mask, scale=None, *, backend: str = "python",
     (arbitrary geometry in, lane-aligned tiles inside); ``"auto"``
     resolves via ``resolve_backend`` on the stream size.
     """
-    backend = resolve_backend(backend, packets.size, interpret)
+    backend = resolve_backend(backend, packets.size,
+                              kcommon.interpret_mode())
     if backend == "pallas":
         m = mask if scale is None else mask * scale
-        return kops.ltp_dropfill(packets, m, interpret=interpret)
+        return kops.ltp_dropfill(packets, m)
     gate = mask if scale is None else mask * scale
     return packets * gate[:, None].astype(packets.dtype)
 
@@ -108,7 +108,6 @@ def staleness_weights(staleness, damping: float) -> np.ndarray:
 
 def reduce_packet_stream(packets_w, masks_w, ltp: LTPConfig, n_workers: int,
                          *, expected_frac=None, backend: Optional[str] = None,
-                         interpret: Optional[bool] = None,
                          premasked: bool = False, worker_weights=None):
     """The PS-side hot loop: one fused masked multi-worker reduction.
 
@@ -117,8 +116,8 @@ def reduce_packet_stream(packets_w, masks_w, ltp: LTPConfig, n_workers: int,
     ``ltp.compensation`` (paper | count | expected; ``expected`` needs
     ``expected_frac``, the Early-Close target fraction).
 
-    backend="pallas" executes ``kernels.packet_reduce`` — the worker loop
-    is unrolled inside the kernel so each output tile is written once and
+    backend="pallas" executes ``kernels.packet_reduce`` — the workers are
+    summed inside the kernel so each output tile is written once and
     each input tile read once (single HBM pass). backend="python" is the
     jnp reference the kernels are verified against.
 
@@ -134,9 +133,8 @@ def reduce_packet_stream(packets_w, masks_w, ltp: LTPConfig, n_workers: int,
     composes identically with every compensation mode and both backends
     (the stream is pre-scaled before the fused reduction).
     """
-    backend = backend or ltp.sync_backend
-    interpret = ltp.kernel_interpret if interpret is None else interpret
-    backend = resolve_backend(backend, packets_w.size, interpret)
+    backend = resolve_backend(backend or ltp.sync_backend, packets_w.size,
+                              kcommon.interpret_mode())
     comp = ltp.compensation
     if worker_weights is not None:
         w_ = jnp.asarray(worker_weights, jnp.float32)
@@ -144,8 +142,7 @@ def reduce_packet_stream(packets_w, masks_w, ltp: LTPConfig, n_workers: int,
     if backend == "pallas":
         out = kops.ltp_packet_reduce(
             packets_w, masks_w,
-            compensation="count" if comp == "count" else "paper",
-            interpret=interpret)
+            compensation="count" if comp == "count" else "paper")
         if comp == "expected":
             # paper-mode output is sum/W; expected = sum/(W*E[frac])
             ef = (jnp.mean(masks_w) if expected_frac is None
@@ -222,15 +219,13 @@ class LTPSync:
             mask = pk.delivery_mask(plan, k, frac[widx])
             # bubble-fill gate + compensation both dispatch on the backend:
             # fused dropfill tiles under "pallas", jnp reference otherwise
-            sent = apply_delivery(flat, mask, backend=ltp.sync_backend,
-                                  interpret=ltp.kernel_interpret)
+            sent = apply_delivery(flat, mask, backend=ltp.sync_backend)
             tot = jax.lax.psum(sent, dp)
             if ltp.compensation == "count":
                 cnt = jax.lax.psum(mask, dp)
                 out = apply_delivery(tot, jnp.ones_like(cnt),
                                      1.0 / jnp.maximum(cnt, 1.0),
-                                     backend=ltp.sync_backend,
-                                     interpret=ltp.kernel_interpret)
+                                     backend=ltp.sync_backend)
             elif ltp.compensation == "expected":
                 mean_frac = jnp.mean(
                     jnp.where(jnp.asarray(plan.critical), 1.0, jnp.mean(frac))
@@ -249,18 +244,20 @@ class LTPSync:
         if res_in is None:
             def f(g, fr, k):
                 return local(g, fr, k, None)[::2]   # (grads, realized)
-            synced, realized = _shard_map(
+            synced, realized = jax.shard_map(
                 f,
                 mesh=mesh,
                 in_specs=args_specs,
                 out_specs=(self.grad_specs, P()),
+                check_vma=False,
             )(grads, frac, key)
             return synced, None, {"delivered_frac": realized}
-        synced, new_res, realized = _shard_map(
+        synced, new_res, realized = jax.shard_map(
             local,
             mesh=mesh,
             in_specs=args_specs + (res_spec,),
             out_specs=(self.grad_specs, out_res_spec, P()),
+            check_vma=False,
         )(grads, frac, key, res_in)
         return synced, new_res, {"delivered_frac": realized}
 
@@ -326,7 +323,7 @@ def masked_psum_leafwise(grads, key, frac, ltp: LTPConfig, worker_axes,
     """
     widx = jnp.zeros((), jnp.int32)
     for a in worker_axes:
-        widx = widx * compat.axis_size(a) + jax.lax.axis_index(a)
+        widx = widx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     k = jax.random.fold_in(key, widx)
     p = ltp.packet_floats
     leaves, treedef = jax.tree_util.tree_flatten(grads)
@@ -335,8 +332,7 @@ def masked_psum_leafwise(grads, key, frac, ltp: LTPConfig, worker_axes,
     for i, leaf in enumerate(leaves):
         m = _leaf_packet_mask(i, leaf.shape, k, frac[widx], ltp)
         view = apply_delivery(_as_packets(leaf, p), m,
-                              backend=ltp.sync_backend,
-                              interpret=ltp.kernel_interpret)
+                              backend=ltp.sync_backend)
         # per-leaf f32 psum: one all-reduce per tensor with a uniform dtype
         # (XLA:CPU CHECK-fails on one huge mixed-dtype tuple all-reduce —
         # and per-tensor reduces are what a production runtime overlaps
@@ -376,7 +372,7 @@ def masked_rs_update_leafwise(grads, params, m_states, key, frac,
     """
     widx = jnp.zeros((), jnp.int32)
     for a in worker_axes:
-        widx = widx * compat.axis_size(a) + jax.lax.axis_index(a)
+        widx = widx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     k = jax.random.fold_in(key, widx)
     p = ltp.packet_floats
     g_leaves, treedef = jax.tree_util.tree_flatten(grads)

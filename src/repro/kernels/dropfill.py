@@ -15,9 +15,12 @@ buffering).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 from jax.experimental import pallas as pl
+
+from repro.kernels import common
 
 BLOCK_P = 256
 
@@ -28,7 +31,7 @@ def _dropfill_kernel(pkt_ref, gate_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def dropfill(packets, mask, scale, *, interpret: bool = True):
+def dropfill(packets, mask, scale, *, interpret: Optional[bool] = None):
     """packets: (n_packets, payload) f32; mask/scale: (n_packets,) f32.
 
     Requires payload % 128 == 0 and n_packets % BLOCK_P == 0 (the ops.py
@@ -39,14 +42,15 @@ def dropfill(packets, mask, scale, *, interpret: bool = True):
     assert n % BLOCK_P == 0, f"n_packets {n} not a multiple of {BLOCK_P}"
     gate = (mask * scale)[:, None].astype(packets.dtype)
     grid = (n // BLOCK_P,)
+    vma = common.out_vma(packets, gate)
     return pl.pallas_call(
         _dropfill_kernel,
-        out_shape=jax.ShapeDtypeStruct((n, p), packets.dtype),
+        out_shape=jax.ShapeDtypeStruct((n, p), packets.dtype, vma=vma),
         grid=grid,
         in_specs=[
             pl.BlockSpec((BLOCK_P, p), lambda i: (i, 0)),
             pl.BlockSpec((BLOCK_P, 1), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((BLOCK_P, p), lambda i: (i, 0)),
-        interpret=interpret,
+        interpret=common.pallas_interpret(interpret, vma),
     )(packets, gate)

@@ -2,9 +2,9 @@
 
 Handle padding to the kernels' tile constraints (lane-width payload,
 block-multiple packet counts) and strip it on the way out, so callers can
-use arbitrary packet geometries. ``interpret=True`` (the default here)
-executes the kernel body in Python on CPU; on a real TPU pass
-``interpret=False``.
+use arbitrary packet geometries. ``interpret=None`` (the default here)
+compiles the kernels on a TPU and interprets them on any other backend
+(``common.interpret_mode``).
 
 Dispatch cache (DESIGN.md §9): each (interpret, donate) variant of a
 wrapper is built exactly once through ``_variant``; within a variant,
@@ -19,10 +19,12 @@ overwrites it).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import common
 from repro.kernels import dropfill as _df
 from repro.kernels import packet_reduce as _pr
 from repro.kernels import randomk as _rk
@@ -63,14 +65,14 @@ def _dropfill_core(packets, mask, scale, *, interpret: bool):
     return out.astype(packets.dtype)
 
 
-def ltp_dropfill(packets, mask, scale=None, *, interpret: bool = True,
-                 donate: bool = False):
+def ltp_dropfill(packets, mask, scale=None, *,
+                 interpret: Optional[bool] = None, donate: bool = False):
     """packets: (n_packets, payload) any-float; mask: (n_packets,) {0,1};
     scale: optional (n_packets,) compensation. Zero-fills lost packets."""
     if scale is None:
         scale = jnp.ones_like(mask)
-    return _variant("dropfill", bool(interpret), bool(donate))(
-        packets, mask, scale)
+    return _variant("dropfill", common.interpret_mode(interpret),
+                    bool(donate))(packets, mask, scale)
 
 
 def _packet_reduce_core(packets, mask, *, compensation: str,
@@ -84,10 +86,11 @@ def _packet_reduce_core(packets, mask, *, compensation: str,
 
 
 def ltp_packet_reduce(packets, mask, *, compensation: str = "paper",
-                      interpret: bool = True, donate: bool = False):
+                      interpret: Optional[bool] = None,
+                      donate: bool = False):
     """packets: (W, n_packets, payload); mask: (W, n_packets)."""
-    return _variant("packet_reduce", bool(interpret), bool(donate),
-                    compensation)(packets, mask)
+    return _variant("packet_reduce", common.interpret_mode(interpret),
+                    bool(donate), compensation)(packets, mask)
 
 
 def _randomk_core(x, u, k_frac, *, interpret: bool):
@@ -107,6 +110,7 @@ def _randomk_core(x, u, k_frac, *, interpret: bool):
     return out.reshape(-1)[:n].reshape(orig_shape)
 
 
-def randomk_sparsify(x, u, k_frac, *, interpret: bool = True):
+def randomk_sparsify(x, u, k_frac, *, interpret: Optional[bool] = None):
     """Elementwise Random-k keep mask via uniforms ``u`` (same shape)."""
-    return _variant("randomk", bool(interpret), False)(x, u, k_frac)
+    return _variant("randomk", common.interpret_mode(interpret), False)(
+        x, u, k_frac)

@@ -10,10 +10,13 @@ tensor is never materialized twice.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import common
 
 BLOCK_R = 256
 BLOCK_C = 512
@@ -26,16 +29,17 @@ def _randomk_kernel(x_ref, u_ref, k_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def randomk(x, u, k_frac, *, interpret: bool = True):
+def randomk(x, u, k_frac, *, interpret: Optional[bool] = None):
     """x, u: (rows, cols) with rows % BLOCK_R == 0, cols % BLOCK_C == 0;
     k_frac: scalar in [0,1]. Returns x sparsified."""
     r, c = x.shape
     assert r % BLOCK_R == 0 and c % BLOCK_C == 0, (r, c)
     k = jnp.full((1, 1), k_frac, jnp.float32)
     grid = (r // BLOCK_R, c // BLOCK_C)
+    vma = common.out_vma(x, u, k)
     return pl.pallas_call(
         _randomk_kernel,
-        out_shape=jax.ShapeDtypeStruct((r, c), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((r, c), x.dtype, vma=vma),
         grid=grid,
         in_specs=[
             pl.BlockSpec((BLOCK_R, BLOCK_C), lambda i, j: (i, j)),
@@ -43,5 +47,5 @@ def randomk(x, u, k_frac, *, interpret: bool = True):
             pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
         ],
         out_specs=pl.BlockSpec((BLOCK_R, BLOCK_C), lambda i, j: (i, j)),
-        interpret=interpret,
+        interpret=common.pallas_interpret(interpret, vma),
     )(x, u, k)

@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.config import LTPConfig
 from repro.configs import ARCH_IDS, get_config
 from repro.launch import hlo_analysis
@@ -251,7 +250,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool, ltp: bool = False,
             fn, args, specs = build_decode(cfg, shape, mesh)
         shardings = to_named(mesh, specs)
         t0 = time.time()
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(fn, in_shardings=shardings).lower(*args)
         rec["lower_s"] = round(time.time() - t0, 1)
         t0 = time.time()

@@ -11,19 +11,21 @@ the dry-run sets XLA_FLAGS before importing anything else).
 """
 from __future__ import annotations
 
+import jax
+
+
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with Auto axis types: GSPMD places whatever the
+    code does not constrain (``jax.make_mesh`` defaults to Explicit)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
-    from repro import compat
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
-
-
-def make_host_mesh(n_data: int = 1, n_model: int = 1):
-    """Small mesh over however many host devices exist (tests/examples)."""
-    from repro import compat
-    return compat.make_mesh((n_data, n_model), ("data", "model"))
+    return make_mesh(shape, axes)
 
 
 # TPU v5e hardware constants for the roofline (per chip)

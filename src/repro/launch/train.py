@@ -28,6 +28,8 @@ from repro.checkpoint import save_checkpoint
 from repro.config import LTPConfig, NetConfig, TrainConfig
 from repro.configs import get_config, get_reduced
 from repro.data import SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models import build
 from repro.optim import make_optimizer
 from repro.train import PSTrainer
@@ -53,6 +55,7 @@ def main(argv=None) -> int:
                     help="sharded mode: data-axis size (0 = all devices)")
     ap.add_argument("--ckpt", default="")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = (get_reduced if args.reduced else get_config)(args.arch)
     cfg = cfg.replace(dtype="float32")
@@ -78,16 +81,15 @@ def main(argv=None) -> int:
 
     # sharded mode
     n_data = args.n_data or jax.device_count()
-    from repro import compat
-    mesh = compat.make_mesh((n_data, jax.device_count() // n_data),
-                            ("data", "model"))
+    mesh = make_mesh((n_data, jax.device_count() // n_data),
+                     ("data", "model"))
     print(f"mesh: {dict(mesh.shape)}; LTP workers = data axis ({n_data})")
     batch_specs = {"tokens": P("data"), "labels": P("data")}
     step = make_ltp_train_step(api, opt, mesh, ltp, ("data",), batch_specs)
     state = init_state(api, opt, jax.random.PRNGKey(0))
     key = jax.random.PRNGKey(1)
     frac = jnp.ones((n_data,))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for s in range(args.steps):
             b = lm.train_batch(args.batch, args.seq, s)
             b = {k: jnp.asarray(v) for k, v in b.items()}

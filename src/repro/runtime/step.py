@@ -99,7 +99,6 @@ def _build_fused_step(api, opt: Optimizer, ltp: LTPConfig, plan, w: int,
                 sent = ls.apply_delivery(
                     flat_w.reshape(w * plan.n_packets, plan.packet_floats),
                     masks.reshape(-1), backend=ltp.sync_backend,
-                    interpret=ltp.kernel_interpret,
                 ).reshape(flat_w.shape)
                 new_residual = flat_w - sent
                 mean_flat = ls.reduce_packet_stream(
@@ -149,8 +148,7 @@ def build_ef_gate_fn(ltp: LTPConfig):
         @jax.jit
         def gate(flat, residual, mask):
             flat = flat + residual
-            sent = ls.apply_delivery(flat, mask, backend=ltp.sync_backend,
-                                     interpret=ltp.kernel_interpret)
+            sent = ls.apply_delivery(flat, mask, backend=ltp.sync_backend)
             return sent, flat - sent
 
         return gate

@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.config import LTPConfig
 from repro.core import ltp_sync as ls
 from repro.models.api import ModelApi
@@ -77,7 +76,11 @@ def make_ltp_train_step(api: ModelApi, opt: Optimizer, mesh,
     n_workers = 1
     for a in worker_axes:
         n_workers *= mesh.shape[a]
-    ctx = ShardCtx(mesh, exclude=worker_axes)
+    # Pallas TPU kernels lower only where every mesh axis is manual. An
+    # axis of size 1 has nothing to partition, so it is made manual too.
+    manual = set(worker_axes) | {a for a in mesh.axis_names
+                                 if mesh.shape[a] == 1}
+    ctx = ShardCtx(mesh, exclude=tuple(manual))
 
     def restrict(spec: P) -> P:
         out = []
@@ -120,13 +123,13 @@ def make_ltp_train_step(api: ModelApi, opt: Optimizer, mesh,
     def _zero_step(state: TrainState, batch, frac, key, lr):
         n_leaves = len(state.opt_state["m_pkts"])
         m_specs = [P(worker_spec, None)] * n_leaves
-        deltas, m_pkts, mstep, loss, realized = compat.shard_map(
+        deltas, m_pkts, mstep, loss, realized = jax.shard_map(
             inner_zero,
             mesh=mesh,
             in_specs=(rep, m_specs, rep, batch_specs, rep, rep, rep),
             out_specs=(m_specs, m_specs, rep, rep, rep),
-            axis_names=set(worker_axes),
-            check=True,
+            axis_names=manual,
+            check_vma=True,
         )(state.params, state.opt_state["m_pkts"], state.step, batch, frac,
           key, lr)
         # apply the worker-sharded packet deltas in auto land (GSPMD
@@ -147,13 +150,13 @@ def make_ltp_train_step(api: ModelApi, opt: Optimizer, mesh,
     def step(state: TrainState, batch, frac, key, lr):
         if isinstance(state.opt_state, dict) and "m_pkts" in state.opt_state:
             return _zero_step(state, batch, frac, key, lr)
-        params, opt_state, mstep, loss, realized = compat.shard_map(
+        params, opt_state, mstep, loss, realized = jax.shard_map(
             inner,
             mesh=mesh,
             in_specs=(rep, rep, rep, batch_specs, rep, rep, rep),
             out_specs=(rep, rep, rep, rep, rep),
-            axis_names=set(worker_axes),
-            check=True,
+            axis_names=manual,
+            check_vma=True,
         )(state.params, state.opt_state, state.step, batch, frac, key, lr)
         return (
             TrainState(params, opt_state, mstep),

@@ -1,4 +1,5 @@
-"""Per-kernel shape/dtype sweeps vs the pure-jnp oracles (interpret=True)."""
+"""Per-kernel shape/dtype sweeps vs the pure-jnp oracles (interpreted off
+the TPU)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -94,3 +95,19 @@ def test_ops_donate_variants_match_and_cache_separately():
         ops._variant("dropfill", True, False)
     assert ops._variant("dropfill", True, False) is not \
         ops._variant("dropfill", True, True)
+
+
+def test_interpreter_choice_follows_platform_and_vma():
+    """Off the TPU: the generic interpreter, or the TPU interpreter for a
+    call whose output varies over manual mesh axes; an explicit
+    ``interpret=False`` always compiles."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from repro.kernels import common
+
+    assert jax.default_backend() != "tpu"
+    assert common.interpret_mode() is True
+    assert common.pallas_interpret(None, frozenset()) is True
+    assert isinstance(common.pallas_interpret(None, frozenset({"data"})),
+                      pltpu.InterpretParams)
+    assert common.pallas_interpret(False, frozenset({"data"})) is False

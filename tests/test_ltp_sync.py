@@ -15,11 +15,11 @@ from repro.core import packets as pk
 N_DEV = jax.device_count()
 
 
-from repro import compat
+from repro.launch.mesh import make_mesh
 
 
 def _mesh(shape, axes):
-    return compat.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 @pytest.fixture(scope="module")
@@ -103,11 +103,11 @@ def test_leafwise_sync_full_delivery_identity():
     def inner(g, frac, key):
         return ls.masked_psum_leafwise(g, key, frac, ltp, ("data",), 1)
 
-    out, realized = compat.shard_map(
+    out, realized = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(), grads), P(), P()),
         out_specs=(jax.tree.map(lambda _: P(), grads), P()),
-        axis_names={"data"}, check=True,
+        axis_names={"data"}, check_vma=True,
     )(grads, jnp.ones((1,)), jax.random.PRNGKey(0))
     np.testing.assert_allclose(out["w"], grads["w"], rtol=1e-6)
     assert float(realized) == 1.0

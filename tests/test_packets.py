@@ -91,8 +91,8 @@ def test_delivery_mask_rate(frac, seed):
 
 
 def test_local_plan_shapes():
-    from repro import compat
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
     from jax.sharding import PartitionSpec as P
     sds = {"w": jax.ShapeDtypeStruct((64, 32), jnp.float32)}
     plan = pk.local_plan(sds, {"w": P(None, None)}, mesh, packet_floats=8)

@@ -8,12 +8,12 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.config import LTPConfig, NetConfig, TrainConfig
 from repro.configs import get_config
 from repro.core import ltp_sync as ls
 from repro.core import make_ltp_sync
 from repro.core import packets as pk
+from repro.launch.mesh import make_mesh
 from repro.models import build
 
 
@@ -76,7 +76,7 @@ def test_apply_delivery_backends_agree_any_geometry(payload):
 def test_ltp_sync_shard_map_backends_agree(comp):
     """The shard_map-wrapped LTPSync path (bubble-fill + compensation gates
     through dropfill under "pallas") matches the reference."""
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     grads = {"w": jnp.arange(512, dtype=jnp.float32).reshape(32, 16) / 100,
              "b": jnp.linspace(-1, 1, 24)}
     specs = {"w": P(), "b": P()}

@@ -185,11 +185,14 @@ def test_ltpconfig_runtime_view():
 
 def test_with_runtime_overlay():
     base = LTPConfig()
-    rc = RuntimeConfig(staleness_comp=0.7, sync_backend="jit",
-                       kernel_interpret=False)
+    rc = RuntimeConfig(staleness_comp=0.7, sync_backend="jit")
     merged = base.with_runtime(rc)
     assert merged.staleness_comp == 0.7
-    assert merged.sync_backend == "jit" and not merged.kernel_interpret
+    assert merged.sync_backend == "jit"
+    # the platform decides interpret mode (kernels.common.interpret_mode);
+    # neither config carries it
+    assert not hasattr(merged, "kernel_interpret")
+    assert not hasattr(rc, "kernel_interpret")
     # protocol fields untouched
     assert merged.data_pct_threshold == base.data_pct_threshold
     assert merged.deadline_c_ms == base.deadline_c_ms

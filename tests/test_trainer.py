@@ -6,10 +6,10 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.config import LTPConfig
 from repro.configs import get_reduced
 from repro.core import ltp_sync as ls
+from repro.launch.mesh import make_mesh
 from repro.models import build
 from repro.models.api import demo_inputs
 from repro.optim import sgd_momentum
@@ -20,7 +20,7 @@ from repro.train.trainer import (
 
 
 def _mesh():
-    return compat.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 @pytest.fixture(scope="module")
@@ -42,7 +42,7 @@ def test_ltp_full_delivery_matches_plain(setup):
     s_plain, m_plain = plain(state, batch, lr)
 
     ltp_cfg = LTPConfig(packet_floats=128)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         step = make_ltp_train_step(api, opt, mesh, ltp_cfg, ("data",),
                                    jax.tree.map(lambda _: P(), batch))
         s_ltp, m_ltp = step(state, batch, jnp.ones((1,)),
@@ -65,7 +65,7 @@ def test_ltp_zero_variant_matches_psum_variant(setup):
     frac = jnp.full((1,), 0.7)
     key = jax.random.PRNGKey(3)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         step = make_ltp_train_step(api, opt, mesh, ltp_cfg, ("data",),
                                    batch_specs)
         s_psum, _ = step(state, batch, frac, key, lr)

@@ -13,7 +13,8 @@ static checks over ``src/``:
                   sim-registered closures in runtime/ carry a staleness
                   guard
   hotpath         functions marked ``# replint: hotpath`` allocate no
-                  closures / comprehensions / f-strings off-tracker
+                  closures / comprehensions / f-strings off-tracker,
+                  and open no profiler span
   frozen-config   frozen dataclasses in config.py stay hashable
   design-ref      §N citations into DESIGN.md resolve to real sections
 

@@ -476,6 +476,7 @@ def check_gen_fence(ctx: FileContext) -> Iterable[Finding]:
 
 
 _COMP_NODES = (ast.ListComp, ast.SetComp, ast.DictComp, ast.GeneratorExp)
+_SPANS = {"TraceAnnotation", "StepTraceAnnotation"}
 
 
 def _tracker_guarded(test: ast.AST) -> bool:
@@ -528,11 +529,23 @@ def _hot_violations(fn: ast.AST, ctx: FileContext,
 
     for stmt in fn.body:
         visit(stmt)
+    # a profiler span costs about a microsecond even with the profiler
+    # off: per packet or event that is a share of the run (DESIGN.md
+    # §12.5), so none is opened here, on the tracker arm or off it
+    for node in ast.walk(fn):
+        if isinstance(node, ast.Call):
+            chain = _dotted(node.func)
+            if chain and chain[-1] in _SPANS:
+                out.append(Finding(
+                    "hotpath", ctx.path, node.lineno, node.col_offset,
+                    f"hot path opens a profiler span ({chain[-1]}) per "
+                    f"call; open it once a round, in the caller"))
 
 
 @register("hotpath",
           "functions marked '# replint: hotpath' may not allocate "
-          "closures, comprehensions, or f-strings off the tracker arm")
+          "closures, comprehensions, or f-strings off the tracker arm, "
+          "nor open a profiler span")
 def check_hotpath(ctx: FileContext) -> Iterable[Finding]:
     hot = ctx.pragmas.hotpath_lines
     if not hot:

@@ -44,6 +44,7 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.config import (
     FaultConfig,
@@ -68,6 +69,7 @@ from repro.net.netfaults import (
 )
 from repro.net.scenarios import GatherSpec
 from repro.net.simcore import PERF, Sim
+from repro.obs import spans
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.tracker import make_tracker
 from repro.net.topology import resolve_topology
@@ -92,6 +94,13 @@ from repro.runtime.policies import (
 )
 from repro.runtime.telemetry import Telemetry
 from repro.runtime.transport import AnalyticPerWorkerNet, DESTransport
+
+
+def _nbytes(*trees) -> int:
+    """Bytes the device holds of the host values a span uploads: JAX
+    makes each element 32 bits wide."""
+    return 4 * sum(np.size(x)
+                   for t in trees for x in jax.tree_util.tree_leaves(t))
 
 
 class _BSPRound:
@@ -371,9 +380,12 @@ class ClusterRuntime:
         # this worker fetched — staleness is real
         if self._grad_fn is None:
             self._grad_fn = stp.build_worker_grad_fn(self.api, self.plan)
-        loss, flat = self._grad_fn(actor.params_snap,
-                                   self._worker_batch(actor.idx, it))
         worker = actor.idx
+        with TraceAnnotation(spans.STEP_INPUTS, iteration=it,
+                             bytes=_nbytes(self._batches[it]) // self.w):
+            wbatch = self._worker_batch(worker, it)
+        with TraceAnnotation(spans.STEP_DISPATCH, iteration=it):
+            loss, flat = self._grad_fn(actor.params_snap, wbatch)
         # flight registry: teardown paths (worker crash, PS failure) pop
         # entries, and the delivery callback drops itself when its entry
         # is gone — a dead flow can never fold into the model
@@ -384,14 +396,16 @@ class ClusterRuntime:
                              loss=loss, flat=flat):
                 if self._flight.pop((worker, it), None) is None:
                     return
-                stream = np.concatenate(list(masks_ps))
-                row = stp.tile_mask_onto_plan(self.plan, stream)
-                if self.tel.enabled:
-                    self.tel.record(
-                        "masks", self.sim.now, worker=worker, iteration=it,
-                        digest=hashlib.blake2b(
-                            np.ascontiguousarray(masks_ps).tobytes(),
-                            digest_size=8).hexdigest())
+                with TraceAnnotation(spans.MASKS, iteration=it,
+                                     packets=self.plan.n_packets):
+                    stream = np.concatenate(list(masks_ps))
+                    row = stp.tile_mask_onto_plan(self.plan, stream)
+                    if self.tel.enabled:
+                        self.tel.record(
+                            "masks", self.sim.now, worker=worker,
+                            iteration=it, digest=hashlib.blake2b(
+                                np.ascontiguousarray(masks_ps).tobytes(),
+                                digest_size=8).hexdigest())
                 if early:
                     self.tel.record("early_close", self.sim.now,
                                     worker=worker, iteration=it,
@@ -404,12 +418,14 @@ class ClusterRuntime:
                          flat=flat):
                 if self._flight.pop((worker, it), None) is None:
                     return
-                if self.protocol == "ltp":
-                    row = (self._amask_rng.random(self.plan.n_packets)
-                           < frac).astype(np.float32)
-                    row[self.plan.critical] = 1.0
-                else:
-                    row = np.ones(self.plan.n_packets, np.float32)
+                with TraceAnnotation(spans.MASKS, iteration=it,
+                                     packets=self.plan.n_packets):
+                    if self.protocol == "ltp":
+                        row = (self._amask_rng.random(self.plan.n_packets)
+                               < frac).astype(np.float32)
+                        row[self.plan.critical] = 1.0
+                    else:
+                        row = np.ones(self.plan.n_packets, np.float32)
                 if early:
                     self.tel.record("early_close", self.sim.now,
                                     worker=worker, iteration=it,
@@ -420,11 +436,13 @@ class ClusterRuntime:
 
     def _deliver(self, worker: int, it: int, loss, flat, mask_row: np.ndarray,
                  frac: float) -> None:
+        with TraceAnnotation(spans.STEP_INPUTS, iteration=it,
+                             bytes=mask_row.nbytes):
+            mask = jnp.asarray(mask_row)
         g = PendingGrad(
             worker=worker, iteration=it, t_ready=self.sim.now,
             staleness=max(0, self.max_applied_iter - it),
-            payload={"loss": loss, "flat": flat,
-                     "mask": jnp.asarray(mask_row), "frac": frac})
+            payload={"loss": loss, "flat": flat, "mask": mask, "frac": frac})
         self.ps.on_arrival(g)
 
     def on_worker_finished(self, idx: int) -> None:
@@ -537,27 +555,30 @@ class ClusterRuntime:
         """All grads ready: sample the transport models and the Early
         Close controller exactly as the lockstep loop does."""
         it = rnd.iteration
-        shard_bytes = self.model_bytes / self.n_ps
-        samples = [m.sample(shard_bytes) for m in self.gather_models]
-        if self.protocol == "ltp":
-            total = max(1, self.train_cfg.steps)
-            self.controller.set_progress(it / total)
-            close, frac = self.controller.step(samples)
-            bst = close + broadcast_time(self.net, self.model_bytes,
-                                         n_ps=self.n_ps)
-        else:
-            close = max(float(s.completion_times.max()) for s in samples)
-            bst = close + broadcast_time(
-                self.net, self.model_bytes, n_ps=self.n_ps
-            ) * self.gather_models[0].loss_inflation()
-            frac = np.ones(self.w)
-        masks = (stp.draw_delivery_masks(self.plan, self.w, self._mask_rng,
-                                         frac)
-                 if self.protocol == "ltp"
-                 else np.ones((self.w, self.plan.n_packets), np.float32))
-        if self.protocol == "ltp" and float(np.mean(frac)) < 1.0 - 1e-9:
-            self.tel.record("early_close", self.sim.now + close,
-                            iteration=it, delivered=float(np.mean(frac)))
+        with TraceAnnotation(spans.MASKS, iteration=it,
+                             packets=self.w * self.plan.n_packets):
+            shard_bytes = self.model_bytes / self.n_ps
+            samples = [m.sample(shard_bytes) for m in self.gather_models]
+            if self.protocol == "ltp":
+                total = max(1, self.train_cfg.steps)
+                self.controller.set_progress(it / total)
+                close, frac = self.controller.step(samples)
+                bst = close + broadcast_time(self.net, self.model_bytes,
+                                             n_ps=self.n_ps)
+            else:
+                close = max(float(s.completion_times.max())
+                            for s in samples)
+                bst = close + broadcast_time(
+                    self.net, self.model_bytes, n_ps=self.n_ps
+                ) * self.gather_models[0].loss_inflation()
+                frac = np.ones(self.w)
+            masks = (stp.draw_delivery_masks(self.plan, self.w,
+                                             self._mask_rng, frac)
+                     if self.protocol == "ltp"
+                     else np.ones((self.w, self.plan.n_packets), np.float32))
+            if self.protocol == "ltp" and float(np.mean(frac)) < 1.0 - 1e-9:
+                self.tel.record("early_close", self.sim.now + close,
+                                iteration=it, delivered=float(np.mean(frac)))
         # the analytic incast model assumes all W flows start together, so
         # the gather is anchored at the LAST grad-ready (= now, the event
         # that completed the barrier) — under heterogeneous compute the
@@ -577,20 +598,22 @@ class ClusterRuntime:
             # every participant crashed before the gather closed
             self._bsp_round_dissolved()
             return
-        per_shard = sharded.delivery_masks()        # (n_ps, W, n)
-        if self.tel.enabled:
-            self.tel.record(
-                "masks", self.sim.now, iteration=rnd.iteration,
-                digest=hashlib.blake2b(
-                    np.ascontiguousarray(per_shard).tobytes(),
-                    digest_size=8).hexdigest())
-        masks = np.stack([
-            stp.tile_mask_onto_plan(
-                self.plan, np.concatenate([per_shard[p][f]
-                                           for p in range(self.n_ps)]))
-            for f in range(self.w)
-        ])
-        frac = sharded.delivered_fracs()
+        with TraceAnnotation(spans.MASKS, iteration=rnd.iteration,
+                             packets=self.w * self.plan.n_packets):
+            per_shard = sharded.delivery_masks()        # (n_ps, W, n)
+            if self.tel.enabled:
+                self.tel.record(
+                    "masks", self.sim.now, iteration=rnd.iteration,
+                    digest=hashlib.blake2b(
+                        np.ascontiguousarray(per_shard).tobytes(),
+                        digest_size=8).hexdigest())
+            masks = np.stack([
+                stp.tile_mask_onto_plan(
+                    self.plan, np.concatenate([per_shard[p][f]
+                                               for p in range(self.n_ps)]))
+                for f in range(self.w)
+            ])
+            frac = sharded.delivered_fracs()
         close = self.sim.now - rnd.t_first
         bst = close + broadcast_time(self.net, self.model_bytes,
                                      n_ps=self.n_ps)
@@ -608,11 +631,17 @@ class ClusterRuntime:
                 self.api, self.opt, self.ltp, self.plan, self.w,
                 self.protocol)
         lr = lr_at(self.train_cfg, it, self._epoch_steps)
-        (self.params, self.opt_state, self.residual, loss, realized) = \
-            self._fused_step(self.params, self.opt_state, self.residual,
-                             self._shaped_batch(it), jnp.asarray(masks),
-                             jnp.asarray(frac, jnp.float32),
-                             jnp.asarray(lr, jnp.float32))
+        with TraceAnnotation(spans.STEP_INPUTS, iteration=it,
+                             bytes=_nbytes(self._batches[it], masks, frac,
+                                           lr)):
+            batch = self._shaped_batch(it)
+            masks_d = jnp.asarray(masks)
+            frac_d = jnp.asarray(frac, jnp.float32)
+            lr_d = jnp.asarray(lr, jnp.float32)
+        with TraceAnnotation(spans.STEP_DISPATCH, iteration=it):
+            (self.params, self.opt_state, self.residual, loss, realized) = \
+                self._fused_step(self.params, self.opt_state, self.residual,
+                                 batch, masks_d, frac_d, lr_d)
         # the iteration commits when the broadcast lands: history record,
         # params visibility, and the barrier release all happen there.
         # ``t_anchor`` is the gather start (analytic: last grad-ready;
@@ -697,37 +726,46 @@ class ClusterRuntime:
             weights = np.zeros(self.w, np.float32)
             rows_flat, rows_mask, losses = [], [], []
             scale = self.w / len(survivors)
+            shard_bytes = _nbytes(self._batches[it]) // self.w
             for i, wkr in enumerate(survivors):
                 snap = self.workers[wkr].params_snap
-                loss, flat = self._grad_fn(
-                    self.params if snap is None else snap,
-                    self._worker_batch(wkr, it))
-                mask = jnp.asarray(masks[wkr])
-                if self._ef_gate is not None:
-                    flat, new_res = self._ef_gate(
-                        flat, self.residual[wkr], mask)
-                    self.residual = self.residual.at[wkr].set(new_res)
+                with TraceAnnotation(spans.STEP_INPUTS, iteration=it,
+                                     bytes=shard_bytes + masks[wkr].nbytes):
+                    wbatch = self._worker_batch(wkr, it)
+                    mask = jnp.asarray(masks[wkr])
+                with TraceAnnotation(spans.STEP_DISPATCH, iteration=it):
+                    loss, flat = self._grad_fn(
+                        self.params if snap is None else snap, wbatch)
+                    if self._ef_gate is not None:
+                        flat, new_res = self._ef_gate(
+                            flat, self.residual[wkr], mask)
+                        self.residual = self.residual.at[wkr].set(new_res)
                 rows_flat.append(flat)
                 rows_mask.append(mask)
                 weights[i] = scale
                 losses.append(loss)
-            pad = self.w - len(survivors)
-            if pad:
-                rows_flat.append(jnp.zeros((pad, n, p), jnp.float32))
-                rows_mask.append(jnp.zeros((pad, n), jnp.float32))
-                stacked = jnp.concatenate(
-                    [jnp.stack(rows_flat[:-1]), rows_flat[-1]])
-                mrows = jnp.concatenate(
-                    [jnp.stack(rows_mask[:-1]), rows_mask[-1]])
-            else:
-                stacked = jnp.stack(rows_flat)
-                mrows = jnp.stack(rows_mask)
             lr = lr_at(self.train_cfg, it, self._epoch_steps)
             fr = float(np.mean(frac_arr[survivors]))
-            self.params, self.opt_state = self._apply_fn(
-                self.params, self.opt_state, stacked, mrows,
-                jnp.asarray(weights), jnp.asarray(fr, jnp.float32),
-                jnp.asarray(lr, jnp.float32))
+            with TraceAnnotation(spans.STEP_INPUTS, iteration=it,
+                                 bytes=_nbytes(weights, fr, lr)):
+                pad = self.w - len(survivors)
+                if pad:
+                    rows_flat.append(jnp.zeros((pad, n, p), jnp.float32))
+                    rows_mask.append(jnp.zeros((pad, n), jnp.float32))
+                    stacked = jnp.concatenate(
+                        [jnp.stack(rows_flat[:-1]), rows_flat[-1]])
+                    mrows = jnp.concatenate(
+                        [jnp.stack(rows_mask[:-1]), rows_mask[-1]])
+                else:
+                    stacked = jnp.stack(rows_flat)
+                    mrows = jnp.stack(rows_mask)
+                weights_d = jnp.asarray(weights)
+                fr_d = jnp.asarray(fr, jnp.float32)
+                lr_d = jnp.asarray(lr, jnp.float32)
+            with TraceAnnotation(spans.STEP_DISPATCH, iteration=it):
+                self.params, self.opt_state = self._apply_fn(
+                    self.params, self.opt_state, stacked, mrows, weights_d,
+                    fr_d, lr_d)
             loss = jnp.mean(jnp.stack(losses))
             self.version += 1
             self.max_applied_iter = it
@@ -779,30 +817,38 @@ class ClusterRuntime:
         for i, g in enumerate(batch):
             flat, mask = g.payload["flat"], g.payload["mask"]
             if self._ef_gate is not None:
-                flat, new_res = self._ef_gate(flat, self.residual[g.worker],
-                                              mask)
-                self.residual = self.residual.at[g.worker].set(new_res)
+                with TraceAnnotation(spans.STEP_DISPATCH,
+                                     iteration=g.iteration):
+                    flat, new_res = self._ef_gate(
+                        flat, self.residual[g.worker], mask)
+                    self.residual = self.residual.at[g.worker].set(new_res)
             rows_flat.append(flat)
             rows_mask.append(mask)
             weights[i] = 1.0 if pw is None else pw[i]
             fracs.append(g.payload["frac"])
-        pad = self.w - len(batch)   # fixed (W, n, p) shape: compile once
-        if pad:
-            rows_flat.append(jnp.zeros((pad, n, p), jnp.float32))
-            rows_mask.append(jnp.zeros((pad, n), jnp.float32))
-            stacked = jnp.concatenate(
-                [jnp.stack(rows_flat[:-1]), rows_flat[-1]])
-            masks = jnp.concatenate(
-                [jnp.stack(rows_mask[:-1]), rows_mask[-1]])
-        else:
-            stacked = jnp.stack(rows_flat)
-            masks = jnp.stack(rows_mask)
         top_it = max(g.iteration for g in batch)
         lr = lr_at(self.train_cfg, top_it, self._epoch_steps)
-        frac = jnp.asarray(np.mean(fracs), jnp.float32)
-        self.params, self.opt_state = self._apply_fn(
-            self.params, self.opt_state, stacked, masks,
-            jnp.asarray(weights), frac, jnp.asarray(lr, jnp.float32))
+        fr = float(np.mean(fracs))
+        with TraceAnnotation(spans.STEP_INPUTS, iteration=top_it,
+                             bytes=_nbytes(weights, fr, lr)):
+            pad = self.w - len(batch)   # fixed (W, n, p): compile once
+            if pad:
+                rows_flat.append(jnp.zeros((pad, n, p), jnp.float32))
+                rows_mask.append(jnp.zeros((pad, n), jnp.float32))
+                stacked = jnp.concatenate(
+                    [jnp.stack(rows_flat[:-1]), rows_flat[-1]])
+                masks = jnp.concatenate(
+                    [jnp.stack(rows_mask[:-1]), rows_mask[-1]])
+            else:
+                stacked = jnp.stack(rows_flat)
+                masks = jnp.stack(rows_mask)
+            weights_d = jnp.asarray(weights)
+            fr_d = jnp.asarray(fr, jnp.float32)
+            lr_d = jnp.asarray(lr, jnp.float32)
+        with TraceAnnotation(spans.STEP_DISPATCH, iteration=top_it):
+            self.params, self.opt_state = self._apply_fn(
+                self.params, self.opt_state, stacked, masks, weights_d,
+                fr_d, lr_d)
         self.version += 1
         self.max_applied_iter = max(self.max_applied_iter, top_it)
         stale = [g.staleness for g in batch]
@@ -813,7 +859,7 @@ class ClusterRuntime:
         rec = {
             "step": self.version - 1,
             "loss": loss,
-            "delivered": float(np.mean(fracs)),
+            "delivered": fr,
             "staleness": int(max(stale)),
             "n_grads": len(batch),
             "sim_time": self.sim_time,
@@ -1168,7 +1214,8 @@ class ClusterRuntime:
             self._perf0 = PERF.snapshot()
         for wk in self.workers:
             wk.start()
-        self.sim.run(max_events=max_events)
+        with TraceAnnotation(spans.SIM_RUN):
+            self.sim.run(max_events=max_events)
         if self.sim.truncated:
             n_done = sum(1 for wk in self.workers
                          if wk.finished or wk.state == "dead")

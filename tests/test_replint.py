@@ -256,6 +256,38 @@ def test_hotpath_tracker_arm_is_exempt(tmp_path):
     assert len(fs) == 1 and "comprehension" in fs[0].message
 
 
+def test_hotpath_flags_profiler_spans_on_any_arm(tmp_path):
+    fs = _lint(tmp_path, "mod.py", """\
+        import jax
+        from jax.profiler import StepTraceAnnotation
+
+        # replint: hotpath
+        def hot(self, pkt):
+            with jax.profiler.TraceAnnotation("pkt"):
+                self.n += 1
+            if self._h_observe is not None:
+                with StepTraceAnnotation("pkt", step_num=self.n):
+                    self._h_observe(self.n)
+        """, select=["hotpath"])
+    assert _rules(fs) == ["hotpath"] * 2
+    assert all("profiler span" in f.message for f in fs)
+
+
+def test_hotpath_spans_outside_marked_functions_are_fine(tmp_path):
+    fs = _lint(tmp_path, "mod.py", """\
+        from jax.profiler import TraceAnnotation
+
+        # replint: hotpath
+        def hot(self, pkt):
+            self.n += 1
+
+        def per_round(self, it):
+            with TraceAnnotation("ltp.masks", iteration=it):
+                self.hot(it)
+        """, select=["hotpath"])
+    assert fs == []
+
+
 # --------------------------------------------------------------------------
 # frozen-config
 

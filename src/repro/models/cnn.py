@@ -4,6 +4,15 @@ BatchNorm is replaced by per-position channel LayerNorm so the model is
 deterministic under any data sharding (BN's cross-batch statistics would
 couple workers through something other than the gradient sync the paper
 studies).
+
+The norm has a hand-written backward (``jax.custom_vjp``): it computes
+the mean once, keeps only ``(x, mu, rstd, scale)`` for the backward and
+recomputes the normalized input there. Autodiff of the plain formula
+saved and re-read several full-size intermediates of every conv output,
+and those HBM passes, not the convs' arithmetic, took about a quarter of
+the fused training step's device time on a TPU v5e (ResNet-56, 8
+workers of 128 images). The gradient is the exact one; only the
+rounding order differs from autodiff's.
 """
 from __future__ import annotations
 
@@ -28,10 +37,33 @@ def _conv(x, w, stride: int = 1):
     )
 
 
-def _chan_norm(x, scale, offset, eps=1e-5):
+_NORM_EPS = 1e-5
+
+
+@jax.custom_vjp
+def _chan_norm(x, scale, offset):
+    """Normalize ``x`` over its last (channel) axis, then scale and shift."""
+    return _chan_norm_fwd(x, scale, offset)[0]
+
+
+def _chan_norm_fwd(x, scale, offset):
     mu = jnp.mean(x, axis=-1, keepdims=True)
-    var = jnp.var(x, axis=-1, keepdims=True)
-    return (x - mu) * jax.lax.rsqrt(var + eps) * scale + offset
+    xc = x - mu
+    rstd = jax.lax.rsqrt(jnp.mean(xc * xc, axis=-1, keepdims=True) + _NORM_EPS)
+    return xc * rstd * scale + offset, (x, mu, rstd, scale)
+
+
+def _chan_norm_bwd(res, g):
+    x, mu, rstd, scale = res
+    xhat = (x - mu) * rstd
+    gs = g * scale
+    dx = rstd * (gs - jnp.mean(gs, axis=-1, keepdims=True)
+                 - xhat * jnp.mean(gs * xhat, axis=-1, keepdims=True))
+    lead = tuple(range(g.ndim - 1))
+    return dx, jnp.sum(g * xhat, lead), jnp.sum(g, lead)
+
+
+_chan_norm.defvjp(_chan_norm_fwd, _chan_norm_bwd)
 
 
 def _norm_p(c):

@@ -27,7 +27,7 @@ from repro.kernels.dropfill import dropfill
 from repro.kernels.packet_reduce import packet_reduce
 from repro.kernels.randomk import randomk
 from repro.launch.mesh import make_mesh
-from repro.models import build
+from repro.models import build, cnn
 from repro.optim import sgd_momentum
 from repro.runtime import step as stp
 from repro.train.trainer import TrainState, make_ltp_train_step
@@ -157,3 +157,39 @@ def test_sharded_ltp_step_compiles(topo, compiled_kernels):
                     _sds((2,), jnp.uint32, rep), _sds((), jnp.float32, rep))
     assert "tpu_custom_call" in text
     assert "all-reduce" in text
+
+
+def _plain_norm(x, scale, offset, eps=1e-5):
+    """The channel norm's plain formula, left to autodiff."""
+    mu = jnp.mean(x, axis=-1, keepdims=True)
+    var = jnp.var(x, axis=-1, keepdims=True)
+    return (x - mu) * jax.lax.rsqrt(var + eps) * scale + offset
+
+
+def test_chan_norm_backward_cuts_temporaries(one_chip):
+    """A 16-channel basic block of the CNN (conv, norm, relu, conv, norm,
+    skip, relu) at the benchmark's shapes, its parameter gradients mapped
+    over W=8 workers at ``highest``: the norm's closed-form backward needs
+    fewer temporaries and moves fewer bytes than autodiff of the plain
+    formula."""
+    w, batch, c = 8, 128, 16
+    conv = _sds((w, 3, 3, c, c), jnp.float32, one_chip)
+    vec = _sds((w, c), jnp.float32, one_chip)
+    params = {"w1": conv, "s1": vec, "o1": vec,
+              "w2": conv, "s2": vec, "o2": vec}
+    x = _sds((w, batch, 32, 32, c), jnp.float32, one_chip)
+
+    def compiled(norm):
+        def loss(p, x):
+            h = jax.nn.relu(norm(cnn._conv(x, p["w1"]), p["s1"], p["o1"]))
+            h = norm(cnn._conv(h, p["w2"]), p["s2"], p["o2"])
+            return jnp.mean(jax.nn.relu(h + x))
+
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(jax.vmap(jax.grad(loss))).lower(params, x).compile()
+
+    new, old = compiled(cnn._chan_norm), compiled(_plain_norm)
+    assert (new.memory_analysis().temp_size_in_bytes
+            < old.memory_analysis().temp_size_in_bytes)
+    assert (new.cost_analysis()["bytes accessed"]
+            < old.cost_analysis()["bytes accessed"])
